@@ -760,9 +760,13 @@ def main():
     )
     args = ap.parse_args()
 
+    from repro.compile_cache import use_compile_cache
+
+    use_compile_cache()
     if args.smoke:
         return smoke_mesh() if args.strategy == "mesh" else smoke()
 
+    failed = []
     if not args.validate_only:
         from benchmarks import figures
 
@@ -773,11 +777,12 @@ def main():
             t0 = time.time()
             try:
                 fn(quick=not args.full)
-            except Exception as e:  # keep the suite going; failures show below
+            except Exception as e:  # run the other figures; exit nonzero below
                 import traceback
 
                 print(f"[FAILED] {fn.__name__}: {e}")
                 traceback.print_exc()
+                failed.append(fn.__name__)
             print(f"===== {fn.__name__} done in {time.time()-t0:.0f}s =====", flush=True)
 
     print("\n================ PAPER-CLAIM VALIDATION ================")
@@ -787,6 +792,9 @@ def main():
         n_ok += ok
         print(f"[{'PASS' if ok else 'FAIL'}] {name} :: {detail}")
     print(f"{n_ok}/{len(checks)} claims validated")
+    if failed:
+        print(f"[FAILED] figures that raised: {failed}")
+        return 1
     return 0
 
 

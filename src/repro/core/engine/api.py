@@ -86,6 +86,7 @@ def runtime_env() -> dict:
     return {
         "jax_version": jax.__version__,
         "jax_backend": jax.default_backend(),
+        "jax_device_kind": jax.devices()[0].device_kind,
         "jax_device_count": jax.device_count(),
     }
 

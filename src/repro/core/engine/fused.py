@@ -320,7 +320,7 @@ def _omni_window(cfg: SimConfig, bank: Bank, s: SimState) -> SimState:
         a_cnt=hs.a_cnt.at[ztgt].set(zval(hs.a_cnt)),
     )
     hs = hs._replace(
-        slot_key=hs.slot_key.at[slot_c].set(w(claim_valid, keym, hs.slot_key[slot_c])),
+        slot_key=hs_mod.claim_keys(hs.slot_key, slot_c, keym, claim_valid),
         a_cnt=hs.a_cnt.at[slot_c].add(claim_valid.astype(i32)),
         clock=hs.clock.at[slot_c].set(
             w(dispatching, 1, hs.clock[slot_c].astype(i32)).astype(jnp.int8)

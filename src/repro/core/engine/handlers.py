@@ -107,7 +107,7 @@ def _hs_dispatch(cfg, s: SimState, keys, valid) -> SimState:
         a_cnt=zero_if(hs.a_cnt),
     )
     hs = hs._replace(
-        slot_key=hs.slot_key.at[slot].set(jnp.where(valid, keys, hs.slot_key[slot])),
+        slot_key=hs_mod.claim_keys(hs.slot_key, slot, keys, valid),
         a_cnt=hs.a_cnt.at[slot].add(valid.astype(jnp.int32)),
         clock=hs.clock.at[slot].set(1),
     )

@@ -33,7 +33,6 @@ import functools
 
 import jax
 import jax.numpy as jnp
-from jax.experimental.shard_map import shard_map
 
 from repro.dist.sharding import place_worlds, worlds_pspec
 from repro.launch.mesh import make_worlds_mesh
@@ -146,12 +145,12 @@ def _mesh_over(one, bank, xs, bank_axis, ndev):
     xs = place_worlds(xs, mesh)
     if bank_axis is not None:
         bank = place_worlds(bank, mesh)
-    body = shard_map(
+    body = jax.shard_map(
         lambda b, x: _batch_over(one, b, x, bank_axis, "map"),
         mesh=mesh,
         in_specs=(worlds_pspec(bank_axis is not None), worlds_pspec(True)),
         out_specs=worlds_pspec(True),
-        check_rep=False,
+        check_vma=False,
     )
     out = body(bank, xs)
     if Bp != B:

@@ -183,6 +183,27 @@ def find_or_claim_slots(
     return slot, evict
 
 
+def claim_keys(
+    slot_key: jax.Array, slot: jax.Array, keys: jax.Array, valid: jax.Array
+) -> jax.Array:
+    """Store the claimed keys at their `find_or_claim_slots` slots.
+
+    When two keys race for one slot, the later key in the batch wins, as in a
+    scatter applied in order. XLA leaves the winner of a scatter with
+    duplicate indices to the backend, and a TPU may pick differently in each
+    program; so the race is settled here and the scatter's indices are unique
+    (losers and invalid entries rewrite the scratch row with its own value).
+    """
+    capacity = slot_key.shape[0] - 1
+    K = slot.shape[0]
+    later = jnp.arange(K)[None, :] > jnp.arange(K)[:, None]  # [i, j]: j after i
+    lost = jnp.any(later & (slot[:, None] == slot[None, :]) & valid[None, :], axis=1)
+    win = valid & ~lost
+    return slot_key.at[jnp.where(win, slot, capacity)].set(
+        jnp.where(win, keys, slot_key[capacity])
+    )
+
+
 def eq4_masked_w(
     w_lat: jax.Array,
     slot: jax.Array,

@@ -83,14 +83,6 @@ def decode_attention(
     qr = q.reshape(B, KV, G, dh)
     vr8 = valid.astype(jnp.int8)
 
-    params = {}
-    cp = getattr(pltpu, "CompilerParams", None) or getattr(
-        pltpu, "TPUCompilerParams", None
-    )
-    if cp is not None:
-        params["compiler_params"] = cp(
-            dimension_semantics=("parallel", "parallel", "arbitrary")
-        )
     out = pl.pallas_call(
         functools.partial(_kernel, scale=scale, nk=nk),
         grid=(B, KV, nk),
@@ -108,6 +100,8 @@ def decode_attention(
             pltpu.VMEM((G, 1), jnp.float32),
         ],
         interpret=interpret,
-        **params,
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel", "arbitrary")
+        ),
     )(qr, k_cache, v_cache, vr8)
     return out.reshape(B, H, dh)

@@ -153,14 +153,6 @@ def flash_attention(
         window=window,
         chunk_local=chunk_local,
     )
-    params = {}
-    cp = getattr(pltpu, "CompilerParams", None) or getattr(
-        pltpu, "TPUCompilerParams", None
-    )
-    if cp is not None:
-        params["compiler_params"] = cp(
-            dimension_semantics=("parallel", "parallel", "arbitrary")
-        )
     out = pl.pallas_call(
         kernel,
         grid=(B * H, nq, nk),
@@ -177,6 +169,8 @@ def flash_attention(
             pltpu.VMEM((bq, 1), jnp.float32),
         ],
         interpret=interpret,
-        **params,
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel", "arbitrary")
+        ),
     )(qr, kr, vr)
     return out.reshape(B, H, S, dh)

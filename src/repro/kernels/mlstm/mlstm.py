@@ -125,14 +125,6 @@ def mlstm_chunk(
     fq_map = lambda bh, qi, ki: (bh, qi)
     fk_map = lambda bh, qi, ki: (bh, ki)
 
-    params = {}
-    cp = getattr(pltpu, "CompilerParams", None) or getattr(
-        pltpu, "TPUCompilerParams", None
-    )
-    if cp is not None:
-        params["compiler_params"] = cp(
-            dimension_semantics=("parallel", "parallel", "arbitrary")
-        )
     out = pl.pallas_call(
         functools.partial(_kernel, scale=scale, bq=bq, bk=bk, nk=nk),
         grid=(BH, nq, nk),
@@ -152,6 +144,8 @@ def mlstm_chunk(
             pltpu.VMEM((bq, 1), jnp.float32),
         ],
         interpret=interpret,
-        **params,
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel", "arbitrary")
+        ),
     )(qr, kr, vr, F, F, li)  # F twice: q-row view and k-row view
     return out.reshape(B, H, S, dh)

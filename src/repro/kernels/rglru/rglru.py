@@ -34,14 +34,7 @@ def _kernel(la_ref, b_ref, o_ref, h_ref, *, cs: int):
 
     def step(t, h):
         h = jnp.exp(la[t]) * h + b[t]
-        # all-Slice indices: a literal int axis index trips an AttributeError
-        # in this jax version's interpret-mode discharge rule (it assumes
-        # every non-Slice index is an array with .shape)
-        pl.store(
-            o_ref,
-            (pl.dslice(0, 1), pl.dslice(t, 1), slice(None)),
-            h[None, None].astype(o_ref.dtype),
-        )
+        o_ref[pl.ds(0, 1), pl.ds(t, 1), :] = h[None, None].astype(o_ref.dtype)
         return h
 
     h = jax.lax.fori_loop(0, cs, step, h_ref[0])
@@ -67,14 +60,6 @@ def rglru_scan(
         bE //= 2
     nc, ne = S // cs, E // bE
 
-    params = {}
-    cp = getattr(pltpu, "CompilerParams", None) or getattr(
-        pltpu, "TPUCompilerParams", None
-    )
-    if cp is not None:
-        params["compiler_params"] = cp(
-            dimension_semantics=("parallel", "parallel", "arbitrary")
-        )
     return pl.pallas_call(
         functools.partial(_kernel, cs=cs),
         grid=(B, ne, nc),
@@ -86,5 +71,7 @@ def rglru_scan(
         out_shape=jax.ShapeDtypeStruct((B, S, E), b.dtype),
         scratch_shapes=[pltpu.VMEM((1, bE), jnp.float32)],
         interpret=interpret,
-        **params,
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel", "arbitrary")
+        ),
     )(log_a, b)

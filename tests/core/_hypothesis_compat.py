@@ -16,7 +16,7 @@ import os
 import pytest
 
 try:
-    from hypothesis import given, settings, strategies as st  # noqa: F401
+    from hypothesis import example, given, settings, strategies as st  # noqa: F401
 
     HAVE_HYPOTHESIS = True
 except ImportError:
@@ -29,6 +29,9 @@ except ImportError:
     st = _LazyStrategies()
 
     def settings(**kw):
+        return lambda f: f
+
+    def example(*a, **kw):
         return lambda f: f
 
     def given(*a, **kw):

@@ -271,6 +271,7 @@ class TestRunResult:
         # satellite: jax runtime recorded in every sweep/smoke entry
         assert entry["jax_version"] == jax.__version__
         assert entry["jax_backend"] == jax.default_backend()
+        assert entry["jax_device_kind"] == jax.devices()[0].device_kind
         assert entry["jax_device_count"] == jax.device_count()
         assert entry["worlds"] == 2 and entry["terminals"] == T
         assert entry["events"] == res.events
@@ -280,6 +281,7 @@ class TestRunResult:
         entry = engine.record_smoke({"events_per_sec_batched": 1.0}, path=path)
         stored = engine.load_bench(path)["smoke"]
         assert stored["jax_backend"] == jax.default_backend()
+        assert stored["jax_device_kind"] == jax.devices()[0].device_kind
         assert stored == entry
 
 

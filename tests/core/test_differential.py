@@ -35,7 +35,7 @@ import jax
 import numpy as np
 import pytest
 
-from _hypothesis_compat import HAVE_HYPOTHESIS, given, settings, st
+from _hypothesis_compat import HAVE_HYPOTHESIS, example, given, settings, st
 from repro.core import engine, workloads
 from repro.core.engine.state import (
     KIND_CRASH,
@@ -69,7 +69,8 @@ def _params(seed: int) -> dict:
     Mirrors the hypothesis strategy below so fixed-seed tier-1 examples and
     generative runs draw from the identical space.
     """
-    rng = np.random.RandomState(seed * 7919 + 13)
+    # RandomState takes seeds below 2**32; the property tier draws up to 2**31-1
+    rng = np.random.RandomState((seed * 7919 + 13) % 2**32)
     shape = SHAPE_POOL[int(rng.randint(len(SHAPE_POOL)))]
     _, _, num_ds, _ = shape
     tie_heavy = bool(rng.randint(3) == 0)  # 1/3 of cases: zero-RTT tie storms
@@ -263,6 +264,7 @@ class TestPropertyDifferential:
     divergence, and `_params` replays it exactly."""
 
     @given(seed=_seeds)
+    @example(seed=542363)  # once overflowed the RandomState seed range
     @settings(max_examples=8, deadline=None, derandomize=True)
     def test_four_mode_bitwise(self, seed):
         _check_case(_params(seed))

@@ -106,6 +106,21 @@ class TestHashHotspot:
         np.testing.assert_array_equal(np.asarray(found), [True, True, True, False])
         np.testing.assert_array_equal(np.asarray(s2[:3]), np.asarray(slot[:3]))
 
+    def test_claim_race_goes_to_the_later_key(self):
+        # two keys racing for slot 3: the later one is stored, as a scatter
+        # applied in order would; invalid entries leave the table alone
+        t = hs.hash_init(9)
+        slot = jnp.asarray([3, 5, 3, 8, 3], jnp.int32)
+        keys = jnp.asarray([10, 20, 30, 40, 50], jnp.int32)
+        valid = jnp.asarray([True, True, True, False, False])
+        want = np.full((9,), -1, np.int32)
+        for s, k, v in zip(np.asarray(slot), np.asarray(keys), np.asarray(valid)):
+            if v:
+                want[s] = k
+        got = hs.claim_keys(t.slot_key, slot, keys, valid)
+        np.testing.assert_array_equal(np.asarray(got), want)
+        assert int(got[3]) == 30 and int(got[8]) == -1
+
     def test_miss_maps_to_scratch(self):
         t = hs.hash_init(33)
         slot, found = hs.lookup_slots(t.slot_key, jnp.asarray([7], jnp.int32), jnp.asarray([True]))
