@@ -34,7 +34,6 @@ import dataclasses
 import itertools
 import json
 import pathlib
-import time
 from typing import Any
 
 import jax
@@ -44,12 +43,13 @@ from repro.core.netmodel import PAPER_RTT_MS
 from repro.core.protocol import PRESETS, ProtocolConfig
 
 from repro.core.engine.batch import _run_jit, _sim_world_fresh
-from repro.core.engine.metrics import drain_stats, summarize, world_index
+from repro.core.engine.metrics import drain_stats, summarize_batch, world_index
 from repro.core.engine.placement import (
     mesh_device_count,
     resolve_strategy,
     simulate_batch,
 )
+from repro.core.engine.spans import span, wall_s
 from repro.core.engine.state import (
     FAULT_COLS,
     INF_US,
@@ -555,7 +555,9 @@ class RunResult:
     metrics: list
     cells: list  # one label dict per cell ([] -> [{}] for single runs)
     strategy: str  # as requested ("auto" preserved — recorded in .save)
-    wall_s: float  # device-call wall time (includes compile on cold cache)
+    # seconds of the device call, the host copy and the summaries (the
+    # `repro.device/gather/summarize` spans; compile included on a cold cache)
+    wall_s: float
     bank: Any = None
     bank_batched: bool = False
     batched: bool = True
@@ -564,6 +566,7 @@ class RunResult:
     # — recorded in .save so BENCH entries distinguish map/vmap/mesh runs
     strategy_resolved: str = ""
     mesh_devices: int = 1
+    phases: dict = dataclasses.field(default_factory=dict)  # span -> seconds
 
     # ---- accessors --------------------------------------------------------
 
@@ -753,11 +756,10 @@ class Simulator:
         """Run ONE world (fused init+run, the scalar map-style path)."""
         self._check_bank(bank, batched=False)
         cfg = self._cfg_for(world.faults)
-        t0 = time.time()
-        states = _sim_world_fresh(cfg, bank, world)
-        states = jax.block_until_ready(states)
-        wall = time.time() - t0
-        m = summarize(cfg, states)
+        phases: dict = {}
+        with span("repro.device", phases):
+            states = jax.block_until_ready(_sim_world_fresh(cfg, bank, world))
+        [m] = summarize_batch(cfg, states, phases)
         assert m["noops"] == 0, ("noop event fired", m["noops"])
         return RunResult(
             cfg=cfg,
@@ -765,12 +767,13 @@ class Simulator:
             metrics=[m],
             cells=[dict(labels or {})],
             strategy="map",
-            wall_s=wall,
+            wall_s=wall_s(phases),
             bank=bank,
             bank_batched=False,
             batched=False,
             strategy_resolved="map",
             mesh_devices=1,
+            phases=phases,
         )
 
     def run_grid(
@@ -789,49 +792,46 @@ class Simulator:
         `placement.resolve_strategy`); `mesh_devices` optionally caps the
         mesh device count (default: every visible device). All strategies are
         bitwise-identical per cell to per-cell `run` (asserted in
-        tests/core/test_api.py and tests/core/test_placement.py).
+        tests/core/test_api.py and tests/core/test_placement.py). The
+        result's `phases` holds the seconds of the call's spans (`spans`).
         """
-        if grid.num_ds != self.cfg.num_ds:
-            raise ValueError(
-                f"grid num_ds={grid.num_ds} != Simulator num_ds={self.cfg.num_ds}"
+        phases: dict = {}  # the result's `phases`; each span adds to it on exit
+        with span("repro.run_grid", phases):
+            if grid.num_ds != self.cfg.num_ds:
+                raise ValueError(
+                    f"grid num_ds={grid.num_ds} != Simulator num_ds={self.cfg.num_ds}"
+                )
+            with span("repro.stack", phases):
+                if grid.banks is not None:
+                    bank = grid.bank_stack()
+                elif bank is None:
+                    raise ValueError("run_grid needs a shared bank or a Grid with banks")
+                bank_batched = grid.banks is not None
+                self._check_bank(bank, batched=bank_batched)
+                worlds = grid.worlds()
+            cfg = self._cfg_for(worlds.faults)
+            resolved = resolve_strategy(strategy)
+            ndev = mesh_device_count(resolved, mesh_devices)
+            states, metrics = simulate_batch(
+                cfg, bank, worlds, bank_batched=bank_batched, strategy=resolved,
+                mesh_devices=ndev, phases=phases,
             )
-        if grid.banks is not None:
-            bank = grid.bank_stack()
-            bank_batched = True
-        elif bank is None:
-            raise ValueError("run_grid needs a shared bank or a Grid with banks")
-        else:
-            bank_batched = False
-        self._check_bank(bank, batched=bank_batched)
-        worlds = grid.worlds()
-        cfg = self._cfg_for(worlds.faults)
-        resolved = resolve_strategy(strategy)
-        ndev = mesh_device_count(resolved, mesh_devices)
-        t0 = time.time()
-        states, metrics = simulate_batch(
-            cfg,
-            bank,
-            worlds,
-            bank_batched=bank_batched,
-            strategy=resolved,
-            mesh_devices=ndev,
-        )
-        wall = time.time() - t0
-        for i, m in enumerate(metrics):
-            assert m["noops"] == 0, (f"grid cell {i}", grid.cells[i], m["noops"])
-        return RunResult(
-            cfg=cfg,
-            states=states,
-            metrics=metrics,
-            cells=[dict(c) for c in grid.cells],
-            strategy=strategy,
-            wall_s=wall,
-            bank=bank,
-            bank_batched=bank_batched,
-            batched=True,
-            strategy_resolved=resolved,
-            mesh_devices=ndev,
-        )
+            for i, m in enumerate(metrics):
+                assert m["noops"] == 0, (f"grid cell {i}", grid.cells[i], m["noops"])
+            return RunResult(
+                cfg=cfg,
+                states=states,
+                metrics=metrics,
+                cells=[dict(c) for c in grid.cells],
+                strategy=strategy,
+                wall_s=wall_s(phases),
+                bank=bank,
+                bank_batched=bank_batched,
+                batched=True,
+                strategy_resolved=resolved,
+                mesh_devices=ndev,
+                phases=phases,
+            )
 
     def resume(
         self,
@@ -864,7 +864,7 @@ class Simulator:
         if mesh_devices is None and resolved == "mesh" and result.mesh_devices > 1:
             mesh_devices = result.mesh_devices
         ndev = mesh_device_count(resolved, mesh_devices)
-        t0 = time.time()
+        phases: dict = {}
         if result.batched:
             states, metrics = simulate_batch(
                 cfg,
@@ -874,23 +874,24 @@ class Simulator:
                 states=result.states,
                 strategy=resolved,
                 mesh_devices=ndev,
+                phases=phases,
             )
         else:
-            states = _run_jit(cfg, result.bank, result.states)
-            states = jax.block_until_ready(states)
-            metrics = [summarize(cfg, states)]
+            with span("repro.device", phases):
+                states = jax.block_until_ready(_run_jit(cfg, result.bank, result.states))
+            metrics = summarize_batch(cfg, states, phases)
             resolved, ndev = "map", 1
-        wall = time.time() - t0
         return RunResult(
             cfg=cfg,
             states=states,
             metrics=metrics,
             cells=result.cells,
             strategy=strategy,
-            wall_s=wall,
+            wall_s=wall_s(phases),
             bank=result.bank,
             bank_batched=result.bank_batched,
             batched=result.batched,
             strategy_resolved=resolved,
             mesh_devices=ndev,
+            phases=phases,
         )

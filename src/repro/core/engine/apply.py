@@ -51,6 +51,7 @@ from repro.core.engine.window import K_EWMA, _window_plan
 if TYPE_CHECKING:
     from repro.core.engine.window import _PlanVals
 
+@jax.named_scope("repro/apply")
 def _apply_window(
     cfg: SimConfig,
     s_: SimState,
@@ -300,53 +301,54 @@ def _apply_window(
     # indices — vmapped scatters serialize per index on CPU, and the four
     # [T,D,K]-wide scatters this block used to issue dominated the whole
     # lockstep iteration.
-    Wc = v.cand_i.shape[0]
-    wr = jnp.arange(Wc, dtype=i32)
-    t_rel = v.cand_t_sub
-    d_rel = v.cand_d_sub
-    rel_act = v.cand_is_sub & f_mask[t_rel, d_rel]
-    if xrel is not None:
-        r0, rt0, rd0 = xrel
-        at0 = (wr == 0) & r0
-        rel_act = rel_act | at0
-        t_rel = jnp.where(at0, rt0, t_rel)
-        d_rel = jnp.where(at0, rd0, d_rel)
-    key_rel = s_.op_key[t_rel]  # [W,K]
-    st_rel = s_.op_state[t_rel].astype(i32)
-    ds_rel = s_.op_ds[t_rel].astype(i32)
-    cancel_rel = rel_act[:, None] & (st_rel != OP_NONE) & (ds_rel == d_rel[:, None])
-    slot_c, found_c = hs_mod.lookup_slots(
-        s_.hs.slot_key,
-        jnp.where(cancel_rel, key_rel, -1).reshape(-1),
-        cancel_rel.reshape(-1),
-    )
-    slot_rel = slot_c.reshape(Wc, K)
-    found_rel = found_c.reshape(Wc, K)
-    lel_td = s_.sub_lel if xlel is None else s_.sub_lel + xlel
-    lel_rel = lel_td[t_rel, d_rel].astype(jnp.float32)[:, None]  # [W,1]
-    new_w = hs_mod.eq4_masked_w(
-        s_.hs.w_lat, slot_rel, found_rel, lel_rel, cfg.alpha_milli
-    )
-    committed_td = due_commit if xcommit is None else due_commit | xcommit
-    committed_rel = committed_td[t_rel, d_rel][:, None] & found_rel
-    # w_lat keeps scatter-SET semantics (duplicated keys inside one footprint
-    # write one identical Eq.(4) value — expressing the set as a packed add
-    # changes XLA's float-fusion context and costs a 1-ulp divergence); the
-    # three counters pack into one scatter-add.
-    upd = found_rel.astype(i32)
-    tbl = jnp.stack([s_.hs.a_cnt, s_.hs.t_cnt, s_.hs.c_cnt], axis=1)  # [C+1, 3]
-    tbl = tbl.at[slot_c].add(
-        jnp.stack([-upd, upd, committed_rel.astype(i32)], axis=2).reshape(-1, 3)
-    )
-    found_fl = found_rel.reshape(-1)
-    hs = s_.hs._replace(
-        w_lat=s_.hs.w_lat.at[slot_c].set(
-            jnp.where(found_fl, new_w.reshape(-1), s_.hs.w_lat[slot_c])
-        ),
-        a_cnt=jnp.maximum(tbl[:, 0], 0),
-        t_cnt=tbl[:, 1],
-        c_cnt=tbl[:, 2],
-    )
+    with jax.named_scope("repro/hotspot"):
+        Wc = v.cand_i.shape[0]
+        wr = jnp.arange(Wc, dtype=i32)
+        t_rel = v.cand_t_sub
+        d_rel = v.cand_d_sub
+        rel_act = v.cand_is_sub & f_mask[t_rel, d_rel]
+        if xrel is not None:
+            r0, rt0, rd0 = xrel
+            at0 = (wr == 0) & r0
+            rel_act = rel_act | at0
+            t_rel = jnp.where(at0, rt0, t_rel)
+            d_rel = jnp.where(at0, rd0, d_rel)
+        key_rel = s_.op_key[t_rel]  # [W,K]
+        st_rel = s_.op_state[t_rel].astype(i32)
+        ds_rel = s_.op_ds[t_rel].astype(i32)
+        cancel_rel = rel_act[:, None] & (st_rel != OP_NONE) & (ds_rel == d_rel[:, None])
+        slot_c, found_c = hs_mod.lookup_slots(
+            s_.hs.slot_key,
+            jnp.where(cancel_rel, key_rel, -1).reshape(-1),
+            cancel_rel.reshape(-1),
+        )
+        slot_rel = slot_c.reshape(Wc, K)
+        found_rel = found_c.reshape(Wc, K)
+        lel_td = s_.sub_lel if xlel is None else s_.sub_lel + xlel
+        lel_rel = lel_td[t_rel, d_rel].astype(jnp.float32)[:, None]  # [W,1]
+        new_w = hs_mod.eq4_masked_w(
+            s_.hs.w_lat, slot_rel, found_rel, lel_rel, cfg.alpha_milli
+        )
+        committed_td = due_commit if xcommit is None else due_commit | xcommit
+        committed_rel = committed_td[t_rel, d_rel][:, None] & found_rel
+        # w_lat keeps scatter-SET semantics (duplicated keys inside one footprint
+        # write one identical Eq.(4) value — expressing the set as a packed add
+        # changes XLA's float-fusion context and costs a 1-ulp divergence); the
+        # three counters pack into one scatter-add.
+        upd = found_rel.astype(i32)
+        tbl = jnp.stack([s_.hs.a_cnt, s_.hs.t_cnt, s_.hs.c_cnt], axis=1)  # [C+1, 3]
+        tbl = tbl.at[slot_c].add(
+            jnp.stack([-upd, upd, committed_rel.astype(i32)], axis=2).reshape(-1, 3)
+        )
+        found_fl = found_rel.reshape(-1)
+        hs = s_.hs._replace(
+            w_lat=s_.hs.w_lat.at[slot_c].set(
+                jnp.where(found_fl, new_w.reshape(-1), s_.hs.w_lat[slot_c])
+            ),
+            a_cnt=jnp.maximum(tbl[:, 0], 0),
+            t_cnt=tbl[:, 1],
+            c_cnt=tbl[:, 2],
+        )
 
     # lock-contention-span metric (commit events, per-event warmup gate)
     lcs_have = due_commit & (s_.first_lock < INF_US) & (
@@ -417,6 +419,7 @@ def _apply_window(
     )
 
 
+@jax.named_scope("repro/plan")
 def _drainable_due(s: SimState) -> jax.Array:
     """Cheap drainability pre-check shared by the map and lockstep drain
     paths: True iff every event due at the minimum timestamp belongs to a
@@ -453,6 +456,7 @@ def _drainable_due(s: SimState) -> jax.Array:
     return clean
 
 
+@jax.named_scope("repro/apply")
 def _drain_step(cfg: SimConfig, bank: Bank, s: SimState) -> SimState:
     """One drain iteration of the scalar (map-lane) hot path: apply the
     maximal conflict-free window of events in one masked pass.

@@ -41,6 +41,7 @@ def run(cfg: SimConfig, bank: Bank, state: SimState) -> SimState:
     else:
         step = _drain_step if cfg.drain else _step
 
+    @jax.named_scope("repro/pick")
     def cond(s: SimState):
         nxt = jnp.min(_times_flat(s))
         return (nxt < jnp.int32(cfg.horizon_us)) & (s.iters < cfg.max_events)

@@ -199,6 +199,7 @@ class _ChainEnts(NamedTuple):
     pfu_valid: jax.Array  # [W] prepare-flush entity exists
 
 
+@jax.named_scope("repro/chain")
 def chain_entities(
     dyn, sst, exec_t, evt_op, cand_t, cand_i, t_w1,
     is_op_c, is_sub_c, op_flat_c, sub_flat_c, t_op_c, k_op_c,
@@ -296,6 +297,7 @@ class _ChainRanks(NamedTuple):
     mrank_pfu: jax.Array  # [W]
 
 
+@jax.named_scope("repro/chain")
 def merged_ranks(cand_t, cand_i, c: _ChainEnts, BIG, maxi) -> _ChainRanks:
     """Candidates + follow-ups in one (time, flat index, is-follow-up)
     order. Keys are unique (invalid follow-ups are keyed past every real
@@ -354,6 +356,7 @@ class _ChainEffects(NamedTuple):
     vote2: jax.Array  # [W] salted vote send time of the prepare flush
 
 
+@jax.named_scope("repro/chain")
 def chain_effects(
     s: SimState, F: int, c: _ChainEnts,
     t_op_c, d_op_c, t_sub_c, d_sub_c, iters_fu, iters_pfu,
@@ -417,6 +420,7 @@ class _Admission(NamedTuple):
     n_chained: jax.Array  # scalar: follow-up entities admitted
 
 
+@jax.named_scope("repro/chain")
 def entity_admission(
     dyn, c: _ChainEnts, r: _ChainRanks, eff: _ChainEffects,
     conf_cand_base, code_cand, n_cand, fu_dup, hit_all, horizon_i, maxi,

@@ -91,6 +91,7 @@ from repro.core.engine.state import (
 from repro.core.engine.apply import _apply_window, _drainable_due
 from repro.core.engine.window import _window_plan
 
+@jax.named_scope("repro/apply")
 def _omni_window(cfg: SimConfig, bank: Bank, s: SimState) -> SimState:
     """Branchless fused windowed drain: plan + apply + single-event fallback
     in ONE straight-line masked pass (no `lax.switch`/`lax.cond`, no
@@ -107,38 +108,40 @@ def _omni_window(cfg: SimConfig, bank: Bank, s: SimState) -> SimState:
     i32 = jnp.int32
     w = jnp.where
 
-    flat = _times_flat(s)
+    with jax.named_scope("repro/pick"):
+        flat = _times_flat(s)
     v = _window_plan(cfg, bank, s)
     use = v.use & _drainable_due(s)
 
     # ---- rank-0 scalar event: the plan's first candidate IS the lex-min
     # event _step would pick (same masked-argmin tie-break) -----------------
-    i0 = v.cand_i[0]
-    t_now0 = flat[i0]
-    is_term0 = i0 < T
-    is_sub0 = ~is_term0 & (i0 < T + T * D)
-    is_op0 = ~is_term0 & ~is_sub0
-    j_sub = i0 - T
-    j_op = i0 - T - T * D
-    t = w(is_term0, i0, w(is_sub0, j_sub // D, j_op // K))
-    idx = w(is_sub0, j_sub % D, w(is_term0, 0, j_op % K))
-    F = cfg.max_faults
-    M0 = T + T * D + T * K
-    if F:
-        # fault tail events: always pinned (use=False), handled by the masked
-        # singleton handlers at the very end of this pass. Heartbeat probes
-        # are conflict-free and drain inside windows; a rank-0 heartbeat only
-        # takes the singleton handler when no window forms (`~use`).
-        is_fault0 = (i0 >= M0) & (i0 < M0 + F)
-        is_hb0 = i0 >= M0 + F
-        is_tail0 = is_fault0 | is_hb0
-        is_op0 = is_op0 & ~is_tail0
-        f_ev0 = jnp.minimum(w(is_fault0, i0 - M0, 0), F - 1)
-        d_hb0 = jnp.minimum(w(is_hb0, i0 - M0 - F, 0), D - 1)
-        t = w(is_tail0, 0, t)
-        idx = w(is_tail0, 0, idx)
-    k_ev = jnp.minimum(idx, K - 1)
-    d_ev = jnp.minimum(idx, D - 1)
+    with jax.named_scope("repro/pick"):
+        i0 = v.cand_i[0]
+        t_now0 = flat[i0]
+        is_term0 = i0 < T
+        is_sub0 = ~is_term0 & (i0 < T + T * D)
+        is_op0 = ~is_term0 & ~is_sub0
+        j_sub = i0 - T
+        j_op = i0 - T - T * D
+        t = w(is_term0, i0, w(is_sub0, j_sub // D, j_op // K))
+        idx = w(is_sub0, j_sub % D, w(is_term0, 0, j_op % K))
+        F = cfg.max_faults
+        M0 = T + T * D + T * K
+        if F:
+            # fault tail events: always pinned (use=False), handled by the masked
+            # singleton handlers at the very end of this pass. Heartbeat probes
+            # are conflict-free and drain inside windows; a rank-0 heartbeat only
+            # takes the singleton handler when no window forms (`~use`).
+            is_fault0 = (i0 >= M0) & (i0 < M0 + F)
+            is_hb0 = i0 >= M0 + F
+            is_tail0 = is_fault0 | is_hb0
+            is_op0 = is_op0 & ~is_tail0
+            f_ev0 = jnp.minimum(w(is_fault0, i0 - M0, 0), F - 1)
+            d_hb0 = jnp.minimum(w(is_hb0, i0 - M0 - F, 0), D - 1)
+            t = w(is_tail0, 0, t)
+            idx = w(is_tail0, 0, idx)
+        k_ev = jnp.minimum(idx, K - 1)
+        d_ev = jnp.minimum(idx, D - 1)
     it0 = s.iters + 1
     salt0 = lambda a: it0 * _SALT_MUL + jnp.int32(a)
     tt_ids = jnp.arange(T, dtype=i32)
@@ -308,25 +311,26 @@ def _omni_window(cfg: SimConfig, bank: Bank, s: SimState) -> SimState:
     dispatching = is_start & ~block & ~force_abort
 
     # hot-table claim (dispatch only; identity-valued writes when off)
-    hs = sx.hs
-    claim_valid = valid_b & dispatching
-    slot_c, evict = hs_mod.find_or_claim_slots(hs.slot_key, keym, claim_valid)
-    ztgt = w(evict, slot_c, cfg.hot_capacity)
-    zval = lambda f: w(dispatching, 0, f[ztgt])
-    hs = hs._replace(
-        w_lat=hs.w_lat.at[ztgt].set(zval(hs.w_lat)),
-        t_cnt=hs.t_cnt.at[ztgt].set(zval(hs.t_cnt)),
-        c_cnt=hs.c_cnt.at[ztgt].set(zval(hs.c_cnt)),
-        a_cnt=hs.a_cnt.at[ztgt].set(zval(hs.a_cnt)),
-    )
-    hs = hs._replace(
-        slot_key=hs_mod.claim_keys(hs.slot_key, slot_c, keym, claim_valid),
-        a_cnt=hs.a_cnt.at[slot_c].add(claim_valid.astype(i32)),
-        clock=hs.clock.at[slot_c].set(
-            w(dispatching, 1, hs.clock[slot_c].astype(i32)).astype(jnp.int8)
-        ),
-    )
-    sx = sx._replace(hs=hs)
+    with jax.named_scope("repro/hotspot"):
+        hs = sx.hs
+        claim_valid = valid_b & dispatching
+        slot_c, evict = hs_mod.find_or_claim_slots(hs.slot_key, keym, claim_valid)
+        ztgt = w(evict, slot_c, cfg.hot_capacity)
+        zval = lambda f: w(dispatching, 0, f[ztgt])
+        hs = hs._replace(
+            w_lat=hs.w_lat.at[ztgt].set(zval(hs.w_lat)),
+            t_cnt=hs.t_cnt.at[ztgt].set(zval(hs.t_cnt)),
+            c_cnt=hs.c_cnt.at[ztgt].set(zval(hs.c_cnt)),
+            a_cnt=hs.a_cnt.at[ztgt].set(zval(hs.a_cnt)),
+        )
+        hs = hs._replace(
+            slot_key=hs_mod.claim_keys(hs.slot_key, slot_c, keym, claim_valid),
+            a_cnt=hs.a_cnt.at[slot_c].add(claim_valid.astype(i32)),
+            clock=hs.clock.at[slot_c].set(
+                w(dispatching, 1, hs.clock[slot_c].astype(i32)).astype(jnp.int8)
+            ),
+        )
+        sx = sx._replace(hs=hs)
     arrive = sx.arrive.at[t].set(w(dispatching | force_abort, t_now0, sx.arrive[t]))
     blocked = sx.blocked.at[t].add(w(block, 1, 0))
     abort_cause = sx.abort_cause.at[t].set(
@@ -452,49 +456,50 @@ def _omni_window(cfg: SimConfig, bank: Bank, s: SimState) -> SimState:
     # (exact `_release_and_grant` semantics; the cancel/hotspot half already
     # ran inside the shared pass via xcancel — grants read the post-cancel
     # table, exactly as the sequential handler does)
-    held = (
-        row_nn2
-        & (s.op_ds[t].astype(i32) == d_rel)
-        & ((s.op_state[t].astype(i32) == OP_EXEC) | (s.op_state[t].astype(i32) == OP_HOLD))
-        & rel_gate_x
-    )
-    rel_keys = w(held, s.op_key[t], -2)
-    flat_state = sx.op_state.reshape(-1).astype(i32)
-    flat_key = sx.op_key.reshape(-1)
-    flat_write = sx.op_write.reshape(-1)
-    flat_enq = sx.op_enq.reshape(-1)
-    flat_ds = sx.op_ds.reshape(-1).astype(i32)
-    holderf = (flat_state == OP_EXEC) | (flat_state == OP_HOLD)
-    waitf = flat_state == OP_WAIT
-    eq = flat_key[None, :] == rel_keys[:, None]  # [K, T*K]
-    rem_x = jnp.any(eq & holderf[None, :] & flat_write[None, :], axis=1)
-    rem_s = jnp.any(eq & holderf[None, :] & ~flat_write[None, :], axis=1)
-    M = held[:, None] & eq & waitf[None, :]
-    exq = w(M & flat_write[None, :], flat_enq[None, :], INF_US)
-    ex_min = jnp.min(exq, axis=1)
-    enq = w(M, flat_enq[None, :], INF_US)
-    grant_s = M & ~flat_write[None, :] & (enq < ex_min[:, None]) & ~rem_x[:, None]
-    any_s = jnp.any(grant_s, axis=1)
-    x_row = jnp.argmin(exq, axis=1)
-    grant_x_ok = (ex_min < INF_US) & ~any_s & ~rem_x & ~rem_s
-    grant_x = (
-        jax.nn.one_hot(x_row, M.shape[1], dtype=bool)
-        & grant_x_ok[:, None]
-        & M
-        & flat_write[None, :]
-    )
-    granted = jnp.any(grant_s | grant_x, axis=0)
-    exec_tg = t_now0 + _exec_us(cfg, s, flat_ds)
-    op_state = w(granted, OP_EXEC, flat_state).astype(jnp.int8).reshape(T, K)
-    op_time = w(granted, exec_tg, sx.op_time.reshape(-1)).reshape(T, K)
-    sx = sx._replace(op_state=op_state, op_time=op_time)
-    # grant-time first_lock via an elementwise group-min (a scatter-min over
-    # [T*K] indices serializes per index under vmap)
-    oh_g = jax.nn.one_hot(sx.op_ds.astype(i32), D, dtype=bool)  # [T,K,D]
-    g_min = jnp.min(
-        jnp.where(granted.reshape(T, K)[:, :, None] & oh_g, t_now0, INF_US), axis=1
-    )
-    sx = sx._replace(first_lock=jnp.minimum(sx.first_lock, g_min))
+    with jax.named_scope("repro/locks"):
+        held = (
+            row_nn2
+            & (s.op_ds[t].astype(i32) == d_rel)
+            & ((s.op_state[t].astype(i32) == OP_EXEC) | (s.op_state[t].astype(i32) == OP_HOLD))
+            & rel_gate_x
+        )
+        rel_keys = w(held, s.op_key[t], -2)
+        flat_state = sx.op_state.reshape(-1).astype(i32)
+        flat_key = sx.op_key.reshape(-1)
+        flat_write = sx.op_write.reshape(-1)
+        flat_enq = sx.op_enq.reshape(-1)
+        flat_ds = sx.op_ds.reshape(-1).astype(i32)
+        holderf = (flat_state == OP_EXEC) | (flat_state == OP_HOLD)
+        waitf = flat_state == OP_WAIT
+        eq = flat_key[None, :] == rel_keys[:, None]  # [K, T*K]
+        rem_x = jnp.any(eq & holderf[None, :] & flat_write[None, :], axis=1)
+        rem_s = jnp.any(eq & holderf[None, :] & ~flat_write[None, :], axis=1)
+        M = held[:, None] & eq & waitf[None, :]
+        exq = w(M & flat_write[None, :], flat_enq[None, :], INF_US)
+        ex_min = jnp.min(exq, axis=1)
+        enq = w(M, flat_enq[None, :], INF_US)
+        grant_s = M & ~flat_write[None, :] & (enq < ex_min[:, None]) & ~rem_x[:, None]
+        any_s = jnp.any(grant_s, axis=1)
+        x_row = jnp.argmin(exq, axis=1)
+        grant_x_ok = (ex_min < INF_US) & ~any_s & ~rem_x & ~rem_s
+        grant_x = (
+            jax.nn.one_hot(x_row, M.shape[1], dtype=bool)
+            & grant_x_ok[:, None]
+            & M
+            & flat_write[None, :]
+        )
+        granted = jnp.any(grant_s | grant_x, axis=0)
+        exec_tg = t_now0 + _exec_us(cfg, s, flat_ds)
+        op_state = w(granted, OP_EXEC, flat_state).astype(jnp.int8).reshape(T, K)
+        op_time = w(granted, exec_tg, sx.op_time.reshape(-1)).reshape(T, K)
+        sx = sx._replace(op_state=op_state, op_time=op_time)
+        # grant-time first_lock via an elementwise group-min (a scatter-min over
+        # [T*K] indices serializes per index under vmap)
+        oh_g = jax.nn.one_hot(sx.op_ds.astype(i32), D, dtype=bool)  # [T,K,D]
+        g_min = jnp.min(
+            jnp.where(granted.reshape(T, K)[:, :, None] & oh_g, t_now0, INF_US), axis=1
+        )
+        sx = sx._replace(first_lock=jnp.minimum(sx.first_lock, g_min))
 
     # =================== terminal finish (ack fan-in / O3 abort) ===========
     fin_done = is_fin_ack_x & (v.done_ack_j[t, d_ev] | v.done_abk_j[t, d_ev])

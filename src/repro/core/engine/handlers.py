@@ -95,6 +95,7 @@ from repro.core.engine.locks import (  # noqa: E402
 # ---------------------------------------------------------------------------
 
 
+@jax.named_scope("repro/hotspot")
 def _hs_dispatch(cfg, s: SimState, keys, valid) -> SimState:
     """Claim hot-table slots for the txn's records and bump a_cnt."""
     hs = s.hs
@@ -114,6 +115,7 @@ def _hs_dispatch(cfg, s: SimState, keys, valid) -> SimState:
     return s._replace(hs=hs)
 
 
+@jax.named_scope("repro/hotspot")
 def _hs_complete_ds(cfg, s: SimState, t, d, committed) -> SimState:
     """Hotspot Eq.(4) update + a_cnt/t_cnt/c_cnt bookkeeping for subtxn (t,d)."""
     mask = (s.op_state[t] != OP_NONE) & (s.op_ds[t] == d.astype(s.op_ds.dtype))
@@ -245,6 +247,7 @@ def _round_inv(s: SimState, t) -> jax.Array:
     return jnp.any(oh & (row & rd)[:, None], axis=0)
 
 
+@jax.named_scope("repro/hotspot")
 def _lel_forecast(cfg, s: SimState, t) -> jax.Array:
     """Eq.(5) per data source for txn t: [D] int32 µs (hot-table lookup)."""
     row = s.op_state[t] != OP_NONE

@@ -28,6 +28,7 @@ from repro.core.engine.state import (
 )
 
 
+@jax.named_scope("repro/locks")
 def _attempt_lock(cfg: SimConfig, s: SimState, t, k) -> SimState:
     """Op (t,k) is at its data source and requests its lock (FIFO-fair).
 
@@ -60,6 +61,7 @@ def _attempt_lock(cfg: SimConfig, s: SimState, t, k) -> SimState:
     return s
 
 
+@jax.named_scope("repro/locks")
 def _grant_decision(held, rel_keys, flat_state, flat_key, flat_write, flat_enq):
     """FIFO-compatible grant set for a release's keys: [T*K] `granted` mask.
 
@@ -93,6 +95,7 @@ def _grant_decision(held, rel_keys, flat_state, flat_key, flat_write, flat_enq):
     return jnp.any(grant_s | grant_x, axis=0)  # [T*K]
 
 
+@jax.named_scope("repro/locks")
 def _release_and_grant(cfg: SimConfig, s: SimState, t, d) -> SimState:
     """Release every lock txn t holds at data source d, cancel its remaining
     ops there, and grant waiting requests FIFO-compatibly."""

@@ -13,17 +13,25 @@ from repro.core.engine.state import (
     SimConfig,
     SimState,
 )
+from repro.core.engine.spans import span
 
 def world_index(states: SimState, i: int) -> SimState:
     """Slice world i out of a batched final state."""
     return jax.tree_util.tree_map(lambda x: x[i], states)
 
 
-def summarize_batch(cfg: SimConfig, states: SimState) -> list:
-    """Host-side metric extraction for a batched final state."""
-    B = int(states.now.shape[0])
-    host = jax.tree_util.tree_map(np.asarray, states)
-    return [summarize(cfg, world_index(host, i)) for i in range(B)]
+def summarize_batch(cfg: SimConfig, states: SimState, phases: dict | None = None) -> list:
+    """Host-side metric extraction: copy the final state (batched over
+    worlds, or one world's) to the host, then one `summarize` dict per
+    world; the two steps are the `repro.gather` and `repro.summarize`
+    spans, timed into ``phases``."""
+    phases = {} if phases is None else phases
+    with span("repro.gather", phases):
+        host = jax.tree_util.tree_map(np.asarray, states)
+    with span("repro.summarize", phases):
+        if host.now.ndim == 0:  # one world's state
+            return [summarize(cfg, host)]
+        return [summarize(cfg, world_index(host, i)) for i in range(host.now.shape[0])]
 
 
 def summarize(cfg: SimConfig, s: SimState) -> dict:

@@ -78,6 +78,7 @@ from repro.core.engine.state import (
 from repro.core.engine.faults import _fault_event, _hb_event
 from repro.core.engine.handlers import _grant_decision, _stagger
 
+@jax.named_scope("repro/apply")
 def _omni_step(cfg: SimConfig, bank: Bank, s: SimState) -> SimState:
     """Branchless all-category dispatch: process the single earliest event as
     ONE straight-line masked pass — no `lax.switch`, no `lax.cond`. Selected
@@ -106,29 +107,30 @@ def _omni_step(cfg: SimConfig, bank: Bank, s: SimState) -> SimState:
     # ---- event pick (identical to _step) ----------------------------------
     F = cfg.max_faults
     M0 = T + T * D + T * K
-    flat = _times_flat(s)
-    i = jnp.argmin(flat).astype(i32)
-    t_now = flat[i]
-    is_term = i < T
-    is_sub = ~is_term & (i < T + T * D)
-    is_op = ~is_term & ~is_sub
-    j_sub = i - T
-    j_op = i - T - T * D
-    t = w(is_term, i, w(is_sub, j_sub // D, j_op // K))
-    idx = w(is_sub, j_sub % D, w(is_term, 0, j_op % K))
-    if F:
-        # fault/heartbeat tail sections (masked handlers run at the very end
-        # of the pass — everything in between is identity for a tail event)
-        is_fault_ev = (i >= M0) & (i < M0 + F)
-        is_hb_ev = i >= M0 + F
-        is_tail = is_fault_ev | is_hb_ev
-        is_op = is_op & ~is_tail
-        f_ev = jnp.minimum(w(is_fault_ev, i - M0, 0), F - 1)
-        d_hb = jnp.minimum(w(is_hb_ev, i - M0 - F, 0), D - 1)
-        t = w(is_tail, 0, t)
-        idx = w(is_tail, 0, idx)
-    k_ev = jnp.minimum(idx, K - 1)
-    d_ev = jnp.minimum(idx, D - 1)
+    with jax.named_scope("repro/pick"):
+        flat = _times_flat(s)
+        i = jnp.argmin(flat).astype(i32)
+        t_now = flat[i]
+        is_term = i < T
+        is_sub = ~is_term & (i < T + T * D)
+        is_op = ~is_term & ~is_sub
+        j_sub = i - T
+        j_op = i - T - T * D
+        t = w(is_term, i, w(is_sub, j_sub // D, j_op // K))
+        idx = w(is_sub, j_sub % D, w(is_term, 0, j_op % K))
+        if F:
+            # fault/heartbeat tail sections (masked handlers run at the very end
+            # of the pass — everything in between is identity for a tail event)
+            is_fault_ev = (i >= M0) & (i < M0 + F)
+            is_hb_ev = i >= M0 + F
+            is_tail = is_fault_ev | is_hb_ev
+            is_op = is_op & ~is_tail
+            f_ev = jnp.minimum(w(is_fault_ev, i - M0, 0), F - 1)
+            d_hb = jnp.minimum(w(is_hb_ev, i - M0 - F, 0), D - 1)
+            t = w(is_tail, 0, t)
+            idx = w(is_tail, 0, idx)
+        k_ev = jnp.minimum(idx, K - 1)
+        d_ev = jnp.minimum(idx, D - 1)
     s = s._replace(now=t_now, iters=s.iters + 1)
 
     # ---- category flags (mirror the handler-id tables) --------------------

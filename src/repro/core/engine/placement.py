@@ -39,6 +39,7 @@ from repro.launch.mesh import make_worlds_mesh
 
 from repro.core.engine.batch import run
 from repro.core.engine.metrics import summarize_batch
+from repro.core.engine.spans import span
 from repro.core.engine.state import SimConfig, SimState, WorldSpec, init_state_world
 
 STRATEGIES = ("map", "vmap", "mesh")
@@ -195,6 +196,7 @@ def simulate_batch(
     states: SimState | None = None,
     strategy: str = "auto",
     mesh_devices: int | None = None,
+    phases: dict | None = None,
 ):
     """Run a batch of worlds as one batched (possibly sharded) device call.
 
@@ -207,6 +209,8 @@ def simulate_batch(
             table; "auto" resolves through `resolve_strategy`.
     mesh_devices: mesh-strategy device count override (default: all visible
             devices); ignored off-mesh.
+    phases: dict the `repro.device`, `repro.gather` and `repro.summarize`
+            spans add their seconds to (see `spans`).
 
     Returns (final_states [B-batched], list of B metric dicts). Fresh runs
     fuse init+run into one compiled call; continuation runs (states given)
@@ -216,8 +220,12 @@ def simulate_batch(
     ndev = mesh_device_count(strategy, mesh_devices)
     cfg = placement_cfg(cfg, strategy)
     bank_axis = 0 if bank_batched else None
-    if states is None:
-        states = _sim_batch_fresh(cfg, bank, worlds, bank_axis, strategy, ndev)
-    else:
-        states = _run_batch(cfg, bank, states, bank_axis, strategy, ndev)
-    return states, summarize_batch(cfg, states)
+    phases = {} if phases is None else phases
+    with span("repro.device", phases):
+        if states is None:
+            states = _sim_batch_fresh(cfg, bank, worlds, bank_axis, strategy, ndev)
+        else:
+            states = _run_batch(cfg, bank, states, bank_axis, strategy, ndev)
+        # the gather below waits here anyway: the block adds no sync
+        states = jax.block_until_ready(states)
+    return states, summarize_batch(cfg, states, phases)

@@ -86,6 +86,7 @@ _TERM_HANDLER = np.full(5, H_NOOP, np.int32)
 _TERM_HANDLER[T_IDLE] = H_START
 _TERM_HANDLER[T_COMMIT_LOG] = H_SEND_COMMITS
 
+@jax.named_scope("repro/apply")
 def _step(cfg: SimConfig, bank: Bank, s: SimState) -> SimState:
     """Process the single earliest event (one fused argmin over all queues).
 
@@ -100,25 +101,26 @@ def _step(cfg: SimConfig, bank: Bank, s: SimState) -> SimState:
     """
     T, D, K, F = cfg.terminals, cfg.num_ds, cfg.max_ops, cfg.max_faults
     M0 = T + T * D + T * K
-    flat = _times_flat(s)
-    i = jnp.argmin(flat).astype(jnp.int32)
-    t_now = flat[i]
-    is_term = i < T
-    is_sub = ~is_term & (i < T + T * D)
-    j_sub = i - T
-    j_op = i - T - T * D
-    t = jnp.where(is_term, i, jnp.where(is_sub, j_sub // D, j_op // K))
-    idx = jnp.where(is_sub, j_sub % D, jnp.where(is_term, 0, j_op % K))
-    if F:
-        is_fault = (i >= M0) & (i < M0 + F)
-        is_hb = i >= M0 + F
-        is_tail = is_fault | is_hb
-        # tail events carry their own index in `t` (fault row / DS id);
-        # clamp the row used for the state-table lookups below
-        t = jnp.where(is_fault, i - M0, jnp.where(is_hb, i - M0 - F, t))
-        t_look = jnp.where(is_tail, 0, t)
-    else:
-        t_look = t
+    with jax.named_scope("repro/pick"):
+        flat = _times_flat(s)
+        i = jnp.argmin(flat).astype(jnp.int32)
+        t_now = flat[i]
+        is_term = i < T
+        is_sub = ~is_term & (i < T + T * D)
+        j_sub = i - T
+        j_op = i - T - T * D
+        t = jnp.where(is_term, i, jnp.where(is_sub, j_sub // D, j_op // K))
+        idx = jnp.where(is_sub, j_sub % D, jnp.where(is_term, 0, j_op % K))
+        if F:
+            is_fault = (i >= M0) & (i < M0 + F)
+            is_hb = i >= M0 + F
+            is_tail = is_fault | is_hb
+            # tail events carry their own index in `t` (fault row / DS id);
+            # clamp the row used for the state-table lookups below
+            t = jnp.where(is_fault, i - M0, jnp.where(is_hb, i - M0 - F, t))
+            t_look = jnp.where(is_tail, 0, t)
+        else:
+            t_look = t
 
     sub_h = jnp.asarray(_SUB_HANDLER)[s.sub_state[t_look, jnp.minimum(idx, D - 1)]]
     op_h = jnp.asarray(_OP_HANDLER)[s.op_state[t_look, jnp.minimum(idx, K - 1)]]

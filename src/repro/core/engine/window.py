@@ -153,6 +153,7 @@ K_EWMA = 4
 PLAN_CAP = 16
 
 
+@jax.named_scope("repro/plan")
 def _window_plan(cfg: SimConfig, bank: Bank, s: SimState) -> _PlanVals:
     """Plan the maximal conflict-free *prefix* (window) of the global event
     order — the generalization of the tie-only drain to events at distinct
@@ -285,11 +286,12 @@ def _window_plan(cfg: SimConfig, bank: Bank, s: SimState) -> _PlanVals:
     # chain targets of candidate exec completions, so they run as [2W, T*K]
     # key queries instead of the [T*K, T*K] comparison matrix the pre-PR-5
     # plan built per iteration.
-    fk = s.op_key.reshape(-1)
-    fw = s.op_write.reshape(-1)
-    fst = st.reshape(-1)
-    holder = (fst == OP_EXEC) | (fst == OP_HOLD)
-    waiting = fst == OP_WAIT
+    with jax.named_scope("repro/locks"):
+        fk = s.op_key.reshape(-1)
+        fw = s.op_write.reshape(-1)
+        fst = st.reshape(-1)
+        holder = (fst == OP_EXEC) | (fst == OP_HOLD)
+        waiting = fst == OP_WAIT
 
     # chain targets of exec completions (first QUEUED op, same DS/round); the
     # chained lock attempt happens at the *source* completion time
@@ -326,26 +328,27 @@ def _window_plan(cfg: SimConfig, bank: Bank, s: SimState) -> _PlanVals:
         qrow = qrow & (kk[None, :] != tk_j[:, None])
     tgt_k = jnp.stack(tgt_ks, axis=1)  # [W, NT]
     tgt_ex = jnp.stack(tgt_exs, axis=1)
-    q_self = jnp.where(is_op_c, op_flat_c, TK)  # sentinel -> padded row
-    q_tgts = jnp.where(
-        is_op_c[:, None] & tgt_ex, t_op_c[:, None] * K + tgt_k, TK
-    )  # [W, NT]
-    fk_pad = jnp.concatenate([fk, jnp.full((1,), -3, fk.dtype)])
-    fw_pad = jnp.concatenate([fw, jnp.zeros((1,), bool)])
-    qs = jnp.concatenate([q_self, q_tgts.T.reshape(-1)])  # [(1+NT)W]
-    keys_q = fk_pad[qs]
-    m_q = keys_q[:, None] == fk[None, :]  # [(1+NT)W, T*K]
-    x_held_q = jnp.any(m_q & (holder & fw)[None, :], axis=1)
-    s_held_q = jnp.any(m_q & (holder & ~fw)[None, :], axis=1)
-    wait_q = jnp.any(m_q & waiting[None, :], axis=1)
-    ok_q = jnp.where(fw_pad[qs], ~x_held_q & ~s_held_q, ~x_held_q) & ~wait_q
-    ok_self_c = ok_q[:W]
-    ok_tgt = ok_q[W:].reshape(NT, W).T  # [W, NT] per-target grants
-    # broadcast the candidate-correct grants back to slot shape (False
-    # elsewhere — nothing beyond the candidates ever reads them)
-    hit_op = q_self[:, None] == ids_tk[None, :]  # [W, T*K]
-    ok = jnp.any(hit_op & ok_self_c[:, None], axis=0).reshape(T, K)
-    ok_chain = jnp.any(hit_op & ok_tgt[:, 0][:, None], axis=0).reshape(T, K)
+    with jax.named_scope("repro/locks"):
+        q_self = jnp.where(is_op_c, op_flat_c, TK)  # sentinel -> padded row
+        q_tgts = jnp.where(
+            is_op_c[:, None] & tgt_ex, t_op_c[:, None] * K + tgt_k, TK
+        )  # [W, NT]
+        fk_pad = jnp.concatenate([fk, jnp.full((1,), -3, fk.dtype)])
+        fw_pad = jnp.concatenate([fw, jnp.zeros((1,), bool)])
+        qs = jnp.concatenate([q_self, q_tgts.T.reshape(-1)])  # [(1+NT)W]
+        keys_q = fk_pad[qs]
+        m_q = keys_q[:, None] == fk[None, :]  # [(1+NT)W, T*K]
+        x_held_q = jnp.any(m_q & (holder & fw)[None, :], axis=1)
+        s_held_q = jnp.any(m_q & (holder & ~fw)[None, :], axis=1)
+        wait_q = jnp.any(m_q & waiting[None, :], axis=1)
+        ok_q = jnp.where(fw_pad[qs], ~x_held_q & ~s_held_q, ~x_held_q) & ~wait_q
+        ok_self_c = ok_q[:W]
+        ok_tgt = ok_q[W:].reshape(NT, W).T  # [W, NT] per-target grants
+        # broadcast the candidate-correct grants back to slot shape (False
+        # elsewhere — nothing beyond the candidates ever reads them)
+        hit_op = q_self[:, None] == ids_tk[None, :]  # [W, T*K]
+        ok = jnp.any(hit_op & ok_self_c[:, None], axis=0).reshape(T, K)
+        ok_chain = jnp.any(hit_op & ok_tgt[:, 0][:, None], axis=0).reshape(T, K)
 
     exec_t = evt_op + _exec_us(cfg, s, d_of)  # [T,K] per-event time basis
     to_t = _lock_wait_deadline(s.dyn, evt_op)
@@ -612,28 +615,29 @@ def _window_plan(cfg: SimConfig, bank: Bank, s: SimState) -> _PlanVals:
         jnp.concatenate([mrank_fu, jnp.zeros((W, 1), i32)], axis=1),
         jnp.concatenate([mrank_pre[:, None], mrank_fu], axis=1),
     )  # [W, NT] merged rank of the toucher
-    tkeys = jnp.concatenate(
-        [fk_pad[q_self], fk_pad[q_tgts].T.reshape(-1), key_rel.reshape(-1)]
-    )  # [(1+NT)W + W*K]
-    tvalid = jnp.concatenate([arr_c, tv.T.reshape(-1), cancel_rel.reshape(-1)])
-    tw = jnp.concatenate(
-        [
-            mrank_pre,
-            tr.T.reshape(-1),
-            jnp.broadcast_to(mrank_pre[:, None], (W, K)).reshape(-1),
-        ]
-    )
-    eq_t = (tkeys[:, None] == tkeys[None, :]) & tvalid[:, None] & tvalid[None, :]
-    dup_t = jnp.any(eq_t & (tw[None, :] < tw[:, None]), axis=1)
-    dup_arr_c = dup_t[:W] & arr_c
-    tg_dup = dup_t[W : W + NT * W].reshape(NT, W).T & tv  # [W, NT]
-    dup_chn_c = tg_dup[:, 0] & ~seed_ca  # pass-1 chain attempt (CX candidate)
-    fu_dup = jnp.where(ca_m, tg_dup[:, :G], tg_dup[:, 1:])  # [W, G] per entity
-    dup_rel_c = jnp.any(dup_t[W + NT * W :].reshape(W, K) & cancel_rel, axis=1)
-    dup_arr = jnp.any(hit_op & dup_arr_c[:, None], axis=0).reshape(T, K)
-    dup_chain = jnp.any(hit_op & dup_chn_c[:, None], axis=0).reshape(T, K)
-    conf_key_sub = jnp.any(hit_sub_rel & dup_rel_c[:, None], axis=0).reshape(T, D)
-    conf_key_op = dup_arr | dup_chain
+    with jax.named_scope("repro/locks"):
+        tkeys = jnp.concatenate(
+            [fk_pad[q_self], fk_pad[q_tgts].T.reshape(-1), key_rel.reshape(-1)]
+        )  # [(1+NT)W + W*K]
+        tvalid = jnp.concatenate([arr_c, tv.T.reshape(-1), cancel_rel.reshape(-1)])
+        tw = jnp.concatenate(
+            [
+                mrank_pre,
+                tr.T.reshape(-1),
+                jnp.broadcast_to(mrank_pre[:, None], (W, K)).reshape(-1),
+            ]
+        )
+        eq_t = (tkeys[:, None] == tkeys[None, :]) & tvalid[:, None] & tvalid[None, :]
+        dup_t = jnp.any(eq_t & (tw[None, :] < tw[:, None]), axis=1)
+        dup_arr_c = dup_t[:W] & arr_c
+        tg_dup = dup_t[W : W + NT * W].reshape(NT, W).T & tv  # [W, NT]
+        dup_chn_c = tg_dup[:, 0] & ~seed_ca  # pass-1 chain attempt (CX candidate)
+        fu_dup = jnp.where(ca_m, tg_dup[:, :G], tg_dup[:, 1:])  # [W, G] per entity
+        dup_rel_c = jnp.any(dup_t[W + NT * W :].reshape(W, K) & cancel_rel, axis=1)
+        dup_arr = jnp.any(hit_op & dup_arr_c[:, None], axis=0).reshape(T, K)
+        dup_chain = jnp.any(hit_op & dup_chn_c[:, None], axis=0).reshape(T, K)
+        conf_key_sub = jnp.any(hit_sub_rel & dup_rel_c[:, None], axis=0).reshape(T, D)
+        conf_key_op = dup_arr | dup_chain
 
     # (b) slot-accurate DM row rules. Row-writers (commit-log flushes and
     #     *triggering* fan-ins) stay forward-exclusive; a fan-in additionally

@@ -154,6 +154,7 @@ def probe_slots_batch(keys: jax.Array, capacity: int, probes: int = 8) -> jax.Ar
     )
 
 
+@jax.named_scope("repro/hotspot")
 def find_or_claim_slots(
     slot_key: jax.Array, keys: jax.Array, valid: jax.Array, probes: int = 8
 ):
@@ -183,6 +184,7 @@ def find_or_claim_slots(
     return slot, evict
 
 
+@jax.named_scope("repro/hotspot")
 def claim_keys(
     slot_key: jax.Array, slot: jax.Array, keys: jax.Array, valid: jax.Array
 ) -> jax.Array:
@@ -204,6 +206,7 @@ def claim_keys(
     )
 
 
+@jax.named_scope("repro/hotspot")
 def eq4_masked_w(
     w_lat: jax.Array,
     slot: jax.Array,
@@ -231,6 +234,7 @@ def eq4_masked_w(
     return jnp.clip(w_old * a + lel * share * (1.0 - a), 0.0, 1e7).astype(jnp.int32)
 
 
+@jax.named_scope("repro/hotspot")
 def lookup_slots(
     slot_key: jax.Array, keys: jax.Array, valid: jax.Array, probes: int = 8
 ) -> tuple[jax.Array, jax.Array]:
