@@ -43,6 +43,7 @@ from repro.core.engine.state import (
     T_COMMIT_WAIT,
     SimConfig,
     SimState,
+    _at_ds,
     _times_flat,
 )
 from repro.core.engine.step import _step
@@ -125,7 +126,7 @@ def _apply_window(
         jnp.where(send_p_wj[:, :, None], v.dt_prepare3, 0), axis=1
     )
     log_term_w = jnp.max(jnp.where(log_wj, v.log_term_j, 0), axis=1)
-    cancel = opn & jnp.take_along_axis(f_mask, d_of, axis=1)
+    cancel = opn & _at_ds(f_mask, d_of)
     if xcancel is not None:
         cancel = cancel | xcancel
 
@@ -142,14 +143,14 @@ def _apply_window(
     op_state = jnp.where(chain_tgt, pick(v.chain_state), op_state)
     op_time = jnp.where(chain_tgt, pick(v.chain_time), op_time)
     op_enq = jnp.where(chain_tgt, pick(evt_op), op_enq)
-    sched_w = jnp.take_along_axis(due_sched, d_of, axis=1)
+    sched_w = _at_ds(due_sched, d_of)
     c_ops_w = sched_w & (st == OP_PENDING) & same_round
     is_first_w = (
         c_ops_w
-        & (jnp.take_along_axis(v.first_c, d_of, axis=1) == kk[None, :])
-        & jnp.take_along_axis(v.has_c, d_of, axis=1)
+        & (_at_ds(v.first_c, d_of) == kk[None, :])
+        & _at_ds(v.has_c, d_of)
     )
-    arr_at_op = jnp.take_along_axis(v.eff_arrival_td, d_of, axis=1)
+    arr_at_op = _at_ds(v.eff_arrival_td, d_of)
     op_state = jnp.where(
         c_ops_w, jnp.where(is_first_w, OP_ENROUTE, OP_QUEUED), op_state
     )

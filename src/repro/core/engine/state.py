@@ -717,12 +717,30 @@ def _salt(s: SimState, a: int) -> jax.Array:
     return s.iters * _SALT_MUL + jnp.int32(a)
 
 
+def _at_ds(x: jax.Array, d: jax.Array) -> jax.Array:
+    """`x` read at data source `d`: `jnp.take_along_axis(x, d, axis=-1)` for
+    `x` `[..., D]` and `d` sharing its leading dimensions (`[T,D]` read by
+    `[T,K]`), `x[d]` for a `[D]` vector and `d` of any shape.
+
+    Precondition: every `d` lies in `[0, D)` (as `op_ds` does). It is read
+    with D-1 selects over the static D, never a gather: under `vmap` a
+    gather lowers to a batched TPU gather that walks its indices one by one
+    (about 10 ns an element), where the selects fuse with their neighbours.
+    """
+    d = jnp.asarray(d)
+    col = x.shape[:-1] + (1,) * (d.ndim - (x.ndim - 1))
+    out = x[..., 0].reshape(col)
+    for j in range(1, x.shape[-1]):
+        out = jnp.where(d == j, x[..., j].reshape(col), out)
+    return jnp.broadcast_to(out, jnp.broadcast_shapes(col, d.shape))
+
+
 def _exec_us(cfg: SimConfig, s: SimState, d: jax.Array) -> jax.Array:
     """Per-op execution time at data source d (scalar or any index array);
     ScalarDB-style middleware CC pays an extra DM round trip per statement
     (at the effective — possibly degraded — link RTT)."""
-    base = s.dyn.exec_us * s.exec_scale_milli[d] // 1000
-    return base + jnp.where(s.dyn.middleware_cc, s.tau_mw_eff[d], 0)
+    base = s.dyn.exec_us * _at_ds(s.exec_scale_milli, d) // 1000
+    return base + jnp.where(s.dyn.middleware_cc, _at_ds(s.tau_mw_eff, d), 0)
 
 
 def _mw_send(s: SimState, on_r: jax.Array, d: jax.Array, t0: jax.Array):

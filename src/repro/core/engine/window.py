@@ -125,6 +125,7 @@ from repro.core.engine.state import (
     _SALT_MUL,
     SimConfig,
     SimState,
+    _at_ds,
     _delay_salted,
     _exec_us,
     _lock_wait_deadline,
@@ -425,7 +426,7 @@ def _window_plan(cfg: SimConfig, bank: Bank, s: SimState) -> _PlanVals:
     eff_arrival_td, fast_disp_td = _tiga_arrival(
         s.dyn, s.clock_skew_us, evt_sub, arrival_td
     )
-    sched_at_op = jnp.take_along_axis(cat_sched, d_of, axis=1)  # [T,K]
+    sched_at_op = _at_ds(cat_sched, d_of)  # [T,K]
     c_ops = sched_at_op & (st == OP_PENDING) & same_round
     cand3 = c_ops[:, :, None] & oh_d
     has_c = jnp.any(cand3, axis=1)  # [T,D]
@@ -510,7 +511,7 @@ def _window_plan(cfg: SimConfig, bank: Bank, s: SimState) -> _PlanVals:
     dt_log = lbase + _delay_salted(s.jitter_milli, ltau, salt_e)
 
     # ---- DS-side commit apply / peer-abort release ------------------------
-    f_at_op = jnp.take_along_axis(f_cat, d_of, axis=1)  # [T,K]
+    f_at_op = _at_ds(f_cat, d_of)  # [T,K]
     cancel_cat = opn & f_at_op  # ops cancelled (this IS the release)
     ack_salt = iters_sub * _SALT_MUL + jnp.where(cat_commit, 47, 53)
     kbase, ktau = link_td(evt_sub)
@@ -569,9 +570,9 @@ def _window_plan(cfg: SimConfig, bank: Bank, s: SimState) -> _PlanVals:
     n_sub = jnp.where(dm_cat, n_fan, n_sub)
     n_sub = jnp.where(pinned_sub, 0, n_sub)
     rd_sched_t = jnp.where(
-        jnp.take_along_axis(aborting_td, d_of, axis=1),
+        _at_ds(aborting_td, d_of),
         INF_US,
-        jnp.take_along_axis(new_sub_time, d_of, axis=1),
+        _at_ds(new_sub_time, d_of),
     )
     pinned_op = ~(cat_arr | cat_exec)  # lock-wait timeouts / unexpected
     n_op = jnp.where(
@@ -594,7 +595,7 @@ def _window_plan(cfg: SimConfig, bank: Bank, s: SimState) -> _PlanVals:
     #     one key twice (a release footprint with a duplicated record) shares
     #     one rank and stays drainable — one event batches with itself
     #     trivially.
-    pos_f_at_op = jnp.take_along_axis(jnp.where(f_cat, pos_sub, BIG), d_of, axis=1)
+    pos_f_at_op = _at_ds(jnp.where(f_cat, pos_sub, BIG), d_of)
     # reverse chain map: tgt3[t,k,j] <=> source op k chains to target op j
     # (gather-based — a scatter here would lower to a per-lane loop under vmap)
     tgt3 = do_chain_cat[:, :, None] & (kk[None, None, :] == nxt[:, :, None])

@@ -9,10 +9,14 @@ only the worker given this file loads the TPU library.
 * `vmap` and `map` on one chip: the two single-chip placements of
   `chip_smoke.py`'s 8-world, 128-terminal grid;
 * `mesh` over four chips: the worlds shard over a 1-D mesh, and since worlds
-  are independent the program must hold no collective.
+  are independent the program must hold no collective;
+* the `vmap` program reads per-data-source values at each op's data source
+  with selects, not gathers (`state._at_ds`).
 """
 
+import math
 import os
+import re
 
 import jax
 import numpy as np
@@ -64,18 +68,31 @@ def _shapes(tree, sharding):
     )
 
 
-@pytest.mark.parametrize("strategy", ["vmap", "map"])
-def test_one_chip_program_compiles(topo, full_size, strategy):
+@pytest.fixture(scope="module")
+def one_chip_program(topo, full_size):
+    """strategy -> the one-chip program at full size, compiled once."""
     sim, bank, worlds = full_size
     one_chip = SingleDeviceSharding(topo.devices[0])
-    compiled = placement._sim_batch_fresh.lower(
-        placement.placement_cfg(sim.cfg, strategy),
-        _shapes(bank, one_chip),
-        _shapes(worlds, one_chip),
-        None,
-        strategy,
-        1,
-    ).compile()
+    done = {}
+
+    def compiled(strategy):
+        if strategy not in done:
+            done[strategy] = placement._sim_batch_fresh.lower(
+                placement.placement_cfg(sim.cfg, strategy),
+                _shapes(bank, one_chip),
+                _shapes(worlds, one_chip),
+                None,
+                strategy,
+                1,
+            ).compile()
+        return done[strategy]
+
+    return compiled
+
+
+@pytest.mark.parametrize("strategy", ["vmap", "map"])
+def test_one_chip_program_compiles(one_chip_program, strategy):
+    compiled = one_chip_program(strategy)
     mem = compiled.memory_analysis()
     used = (mem.argument_size_in_bytes + mem.output_size_in_bytes
             + mem.temp_size_in_bytes)
@@ -100,3 +117,32 @@ def test_mesh_program_has_no_collectives(topo, full_size, monkeypatch):
     hlo = compiled.as_text()
     assert "while" in hlo
     assert [c for c in COLLECTIVES if c in hlo] == []
+
+
+_DEF = re.compile(r"^\s*(?:ROOT )?%([\w.\-]+) = \w+\[([\d,]*)\]", re.M)
+_GATHER = re.compile(r"^\s*(?:ROOT )?%[\w.\-]+ = \w+\[([\d,]*)\]\S* gather\(%([\w.\-]+),", re.M)
+
+
+def _dims(text: str) -> list:
+    return [int(n) for n in text.split(",") if n]
+
+
+def test_vmap_program_reads_op_data_sources_without_gathers(one_chip_program, full_size):
+    """No gather in the optimised `vmap` program reads an operand whose minor
+    dimension is the data-source count and yields one element per op slot
+    (T*K per world): each such read is `state._at_ds`'s selects. Under
+    `vmap` a gather lowers to a batched TPU gather that walks its indices
+    one by one."""
+    sim, _, worlds = full_size
+    cfg = sim.cfg
+    n_worlds = worlds.tau_true.shape[0]
+    hlo = one_chip_program("vmap").as_text()
+    dims = {m.group(1): _dims(m.group(2)) for m in _DEF.finditer(hlo)}
+    gathers = [(_dims(out), dims[src]) for out, src in _GATHER.findall(hlo)]
+    assert gathers, "the pattern finds no gather: the HLO text changed form"
+    per_op_ds = [
+        (out, src) for out, src in gathers
+        if src and src[-1] == cfg.num_ds
+        and math.prod(out) == n_worlds * cfg.terminals * cfg.max_ops
+    ]
+    assert per_op_ds == []
