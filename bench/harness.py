@@ -16,6 +16,7 @@ Metrics are read by the files of ``bench/metrics/``, one per name.
 
 from __future__ import annotations
 
+import contextlib
 import dataclasses
 import importlib.util
 import json
@@ -37,10 +38,12 @@ PATH_TELEMETRY = ("drained", "windows", "win_stops", "fused", "chained")
 # warm-up worlds: every link this slow, so the 10 s horizon holds few events
 # and the warm-up runs the cell's own program in well under a second
 WARM_RTT_MS = 100_000.0
-# the profiler records this much of a traced sweep's start, between these
-# two host markers
+# the profiler records this much of a one-chip traced sweep's start,
+# between these two host markers
 TRACE_S = 1.5
 TRACE_START, TRACE_END = "bench.trace_start", "bench.trace_end"
+# on several chips it records this much from the sweep's device call
+LOOP_S = 0.12
 
 
 def load_spec(root=ROOT) -> dict:
@@ -149,18 +152,22 @@ class Setup:
     inputs: Inputs  # every sweep's worlds
 
 
-def setup(config: dict, traffic: dict) -> Setup:
+def setup(config: dict, traffic: dict, log=lambda msg: None) -> Setup:
     """Draw the traffic's banks, build the simulator and run the cell's own
     program once on slow-link worlds of the same shapes, so that compiling
     (or loading it from the cache) and every small host-side op is done
     before the window opens."""
+    t0 = time.perf_counter()
     with jax.profiler.TraceAnnotation("bench.bank"):
         inputs = sweep_inputs(config, traffic, bank_pool(config, traffic))
+    t1 = time.perf_counter()
     n = len(config["deployment"]["rtt_ms"])
     warm_grid = program_grid(inputs, rtt_ms=(WARM_RTT_MS,) * n)
     sim = simulator(config, warm_grid)
     warm = sim.run_grid(warm_grid)
     jax.block_until_ready(warm.states)
+    log(f"set-up: banks {t1 - t0} s, warm-up {time.perf_counter() - t1} s "
+        f"({int(np.sum(warm.states.iters))} loop iterations; spans {warm.phases})")
     return Setup(sim, warm.strategy_resolved, inputs)
 
 
@@ -198,14 +205,57 @@ def run_sweep(st: Setup, inputs: Inputs, index: int) -> Sweep:
     return Sweep(index, seconds, inputs, res, iters, iters - drained + windows, lane_devices(res.states))
 
 
-def traced_sweep(st: Setup, inputs: Inputs, index: int, log_dir, log=print) -> Sweep:
-    """One sweep under the profiler, which records the first `TRACE_S`
-    seconds of it: the host's preparation, then the loop (a TPU's trace
-    buffers hold a few seconds of this loop, and writing the trace out takes
-    about forty times as long as the span it covers). Markers on the host
-    clock bound the traced span."""
+def trace_plan(chips: int):
+    """(where the trace opens, seconds it records, profiler options) for a
+    cell on ``chips`` chips. One chip: the first `TRACE_S` seconds of the
+    sweep, Python calls included. Several chips: every chip adds a plane of
+    op events and four TPU v5e chips wrote a trace at about 1.9 s per MB,
+    most of it the grid's stacking before the loop; so the trace opens at
+    the sweep's device call and records `LOOP_S` there, with the Python
+    tracer off. On four TPU v5e chips `ycsb.fig5.mesh4`'s call spent about
+    0.05 s sharding its arguments, then each chip ran 231 us trips: 0.16 s
+    held 470 of them, 70 MB written in 138 s, and `LOOP_S` leaves about
+    290. Dearer trips give fewer, never a longer write. The harness's
+    markers and the program's spans stay on the host plane."""
+    opts = jax.profiler.ProfileOptions()
+    opts.enable_hlo_proto = False
+    if chips == 1:
+        return "sweep", TRACE_S, opts
+    opts.python_tracer_level = 0
+    return "device_call", LOOP_S, opts
+
+
+@contextlib.contextmanager
+def at_device_call(fn):
+    """Call ``fn`` where `Simulator.run_grid` makes its device call, after
+    stacking the grid; the program's own `simulate_batch`, which `api`
+    looks up at each call, runs unchanged right after."""
+    from repro.core.engine import api
+
+    inner = api.simulate_batch
+
+    def fn_then_inner(*args, **kw):
+        fn()
+        return inner(*args, **kw)
+
+    api.simulate_batch = fn_then_inner
+    try:
+        yield
+    finally:
+        api.simulate_batch = inner
+
+
+def traced_sweep(st: Setup, inputs: Inputs, index: int, log_dir, chips: int, log=print) -> Sweep:
+    """One sweep under the profiler, which records the span that
+    `trace_plan` gives for ``chips``: on one chip the sweep's start, the
+    host's preparation and then the loop, on several the loop from the
+    device call (a TPU's trace buffers hold a few seconds of this loop, and
+    writing the trace out takes about forty times as long as the span it
+    covers). Markers on the host clock bound the traced span."""
+    opens, span_s, opts = trace_plan(chips)
+    log(f"tracing {span_s} s from the {opens}, Python tracer level {opts.python_tracer_level}")
     lock = threading.Lock()
-    took = []
+    opened, took = [], []
 
     def stop():
         with lock:
@@ -216,20 +266,30 @@ def traced_sweep(st: Setup, inputs: Inputs, index: int, log_dir, log=print) -> S
                 jax.profiler.stop_trace()
                 took.append(time.perf_counter() - t0)
 
-    opts = jax.profiler.ProfileOptions()
-    opts.enable_hlo_proto = False
-    jax.profiler.start_trace(str(log_dir), profiler_options=opts)
-    with jax.profiler.TraceAnnotation(TRACE_START):
-        pass
-    timer = threading.Timer(TRACE_S, stop)
-    timer.start()
+    timer = threading.Timer(span_s, stop)
+
+    def start():
+        t0 = time.perf_counter()
+        jax.profiler.start_trace(str(log_dir), profiler_options=opts)
+        opened.append(time.perf_counter() - t0)
+        with jax.profiler.TraceAnnotation(TRACE_START):
+            pass
+        timer.start()
+
     try:
-        sw = run_sweep(st, inputs, index)
+        if opens == "sweep":
+            start()
+            sw = run_sweep(st, inputs, index)
+        else:
+            with at_device_call(start):
+                sw = run_sweep(st, inputs, index)
+            sw.seconds -= opened[0]  # the profiler's start ran inside the sweep
     finally:
-        timer.cancel()
-        stop()
-        timer.join()
-    log(f"trace written in {took[0]} s")
+        if opened:
+            timer.cancel()
+            stop()
+            timer.join()
+    log(f"trace opened in {opened[0]} s, written in {took[0]} s")
     sw.traced = True
     return sw
 
@@ -245,17 +305,18 @@ def window(st: Setup, seconds: float) -> list:
             return sweeps
 
 
-def traced(st: Setup, log_dir, log=print):
+def traced(st: Setup, log_dir, chips: int, log=print):
     """The traced run's one sweep (`traced_sweep`) and its reduced trace.
     Writing the trace out takes minutes on a TPU, so the traced run holds
     this one sweep and no window."""
-    sw = traced_sweep(st, st.inputs, 0, log_dir, log)
+    sw = traced_sweep(st, st.inputs, 0, log_dir, chips, log)
     t0 = time.perf_counter()
     path = trace.find_xplane(log_dir)
     pd = jax.profiler.ProfileData.from_file(str(path))
     reduced = trace.reduce(pd, TRACE_START, TRACE_END, trace.device_lines(pd))
     log(f"trace of {path.stat().st_size} bytes read in {time.perf_counter() - t0} s; "
-        f"traced span {reduced.window_s} s")
+        f"traced span {reduced.window_s} s; loop trips traced per device "
+        f"{ {d: lp.trips for d, lp in reduced.loops.items()} }")
     return [sw], reduced
 
 
@@ -422,7 +483,8 @@ def run_cell(spec, cell_name, seed, seconds, trace_run, t_start, log_dir=None, r
     logged only, since every run of a cell holds the same worlds."""
     cell, config, traffic = load_cell(spec, cell_name, root)
     meter = CompileMeter()
-    st = setup(config, traffic)
+    log(f"set-up: imports and device start {time.perf_counter() - t_start} s")
+    st = setup(config, traffic, log)
     setup_s = time.perf_counter() - t_start
     setup_compile_s = meter.seconds
     log(f"seed {seed}: the cell's worlds are fixed by its traffic's bank_seeds")
@@ -431,7 +493,7 @@ def run_cell(spec, cell_name, seed, seconds, trace_run, t_start, log_dir=None, r
         f"({meter.count} programs)")
     n0 = meter.count
     if trace_run:
-        sweeps, reduced = traced(st, log_dir, log)
+        sweeps, reduced = traced(st, log_dir, cell["chips"], log)
     else:
         sweeps, reduced = window(st, seconds), None
     in_window = meter.count - n0
@@ -440,6 +502,7 @@ def run_cell(spec, cell_name, seed, seconds, trace_run, t_start, log_dir=None, r
         log(f"sweep {sw.index}: {sw.seconds} s, {int(sw.events.sum())} events, "
             f"{int(sw.trips.sum())} trips")
     used = sorted({d for sw in sweeps for d in sw.lane_device})
+    log(f"worlds on devices {used} of {jax.device_count()}")
     dev0 = jax.devices()[0]
     device = {
         "platform": dev0.platform,

@@ -206,12 +206,12 @@ def main(argv=None) -> int:
         print(f"[scopes] {msg}", file=sys.stderr, flush=True)
 
     use_compile_cache()
-    _, config, traffic = harness.load_cell(harness.load_spec(root), args.workload, root)
+    cell, config, traffic = harness.load_cell(harness.load_spec(root), args.workload, root)
     st = harness.setup(config, traffic)
     log_dir = root / ".bench_out" / "scopes_trace"
     shutil.rmtree(log_dir, ignore_errors=True)
     try:
-        (sw,), reduced = harness.traced(st, log_dir, log)
+        (sw,), reduced = harness.traced(st, log_dir, cell["chips"], log)
         t0 = time.perf_counter()
         pd = jax.profiler.ProfileData.from_file(str(trace.find_xplane(log_dir)))
         lines = trace.device_lines(pd)

@@ -55,6 +55,84 @@ def test_tiny_run_assembles_one_result_line(tiny_root, traced):
     }
 
 
+def test_only_a_multi_chip_traced_run_is_bounded():
+    opens, span_s, opts = harness.trace_plan(1)
+    assert (opens, span_s) == ("sweep", harness.TRACE_S) and span_s == 1.5
+    default = jax.profiler.ProfileOptions()
+    assert (opts.host_tracer_level, opts.python_tracer_level) == (
+        default.host_tracer_level, default.python_tracer_level
+    )
+    assert opts.enable_hlo_proto is False
+    opens, span_s, opts = harness.trace_plan(4)
+    assert (opens, span_s) == ("device_call", harness.LOOP_S)
+    assert opts.python_tracer_level == 0
+    assert opts.host_tracer_level == default.host_tracer_level  # markers and spans stay
+    assert opts.enable_hlo_proto is False
+
+
+FOUR_DEVICES = """
+import json, pathlib, sys, time
+import jax
+sys.path[:0] = [{bench!r}, {root!r}]
+from conftest import TINY, write_tiny_root
+from test_bench_check import _broken_batch
+from bench import harness
+from repro.core.engine import api
+root = pathlib.Path({tmp!r})
+spec = write_tiny_root(root, presets=("ssp", "geotp"), banks=2)
+spec["workloads"][0]["chips"] = 4
+(root / "BENCHMARK.json").write_text(json.dumps(spec))
+run = api.simulate_batch
+for fault in [None, *{faults!r}]:
+    api.simulate_batch = _broken_batch(fault) if fault else run
+    logs = []
+    line = harness.run_cell(harness.load_spec(root), TINY, 2**35 + 3, 0.3, {traced}, time.perf_counter(),
+                            log_dir=root / "trace", root=root, log=logs.append)
+    print("\\n".join(logs))
+    print(json.dumps(line))
+if {traced}:  # the host spans the trace holds
+    from bench import trace
+    pd = jax.profiler.ProfileData.from_file(str(trace.find_xplane(root / "trace")))
+    names = {{e.name for plane in pd.planes for ln in plane.lines for e in ln.events}}
+    print("traced spans", sorted(n for n in names if n.startswith(("repro.", "bench."))))
+"""
+FAULTS = ["unchanged", "half_batch", "no_exchange", "altered"]
+
+
+@pytest.mark.parametrize("traced", [False, True], ids=["untraced", "traced"])
+def test_a_four_device_run_places_worlds_on_every_device(tmp_path, traced):
+    """A four-world cell on four CPU devices: `auto` places it on the mesh,
+    every world is checked, and each broken timed path (untraced only)
+    comes out not correct."""
+    env = dict(os.environ, JAX_PLATFORMS="cpu", XLA_FLAGS="--xla_force_host_platform_device_count=4")
+    faults = [] if traced else FAULTS
+    code = FOUR_DEVICES.format(bench=str(ROOT / "tests" / "bench"), root=str(ROOT),
+                               tmp=str(tmp_path), traced=traced, faults=faults)
+    p = subprocess.run([sys.executable, "-c", code], cwd=ROOT, env=env,
+                       capture_output=True, text=True, timeout=600)
+    assert p.returncode == 0, p.stderr[-4000:]
+    out = p.stdout.splitlines()
+    lines = [json.loads(s) for s in out if s.startswith("{")]
+    assert len(lines) == 1 + len(faults)
+    line = lines[0]
+    assert "placement: auto resolved to mesh" in out
+    assert "worlds on devices [0, 1, 2, 3] of 4" in out
+    assert line["correct"] is True and line["failed"] == 0
+    assert line["device"]["count"] == 4
+    assert all(n["value"] == 0 for n in line["checks"].values()), line["checks"]
+    assert "check: 4 worlds against the reference" in "\n".join(out)
+    if traced:  # one sweep, traced from its device call on: its stacking is left out
+        assert line["attempted"] == 4
+        assert f"tracing {harness.LOOP_S} s from the device_call, Python tracer level 0" in out
+        assert line["metrics"]["lane_imbalance"]["value"] >= 1.0
+        # the trace opened after the stacking had ended
+        spans = [s for s in out if s.startswith("traced spans")]
+        assert spans and "'bench.trace_start'" in spans[0], out
+        assert "repro.stack" not in spans[0] and "bench.sweep" not in spans[0], spans
+    for fault, broken in zip(faults, lines[1:]):
+        assert broken["correct"] is False and broken["failed"] > 0, fault
+
+
 def _run_main(cwd, *args):
     env = dict(os.environ, JAX_PLATFORMS="cpu")
     return subprocess.run(

@@ -74,6 +74,17 @@ def test_us_per_trip_and_idle_share_read_the_loop():
     assert _reader("idle_share")(run) == pytest.approx(100 * (1 - (0.1 + 1.875) / 2.0))
 
 
+@pytest.mark.parametrize("strategy", ["map", "mesh"])
+def test_idle_share_reads_nothing_where_a_device_runs_its_worlds_in_turn(strategy):
+    """The trace holds the first trips of a device's first world only; they
+    stand for no other world's trips."""
+    loops = {d: trace.Loop(pre_busy_s=0.1, busy_s=0.06, trips=250) for d in range(4)}
+    run = _run(loops, seconds=6.8, lane_device=[0, 0, 1, 1, 2, 2, 3, 3], trips=[7000] * 8,
+               strategy=strategy)
+    assert _reader("us_per_trip")(run) == pytest.approx(240.0)
+    assert _reader("idle_share")(run) is None
+
+
 def test_device_readers_find_nothing_without_a_loop():
     run = _run({}, seconds=2.0, lane_device=[0], trips=[10])
     assert _reader("us_per_trip")(run) is None
