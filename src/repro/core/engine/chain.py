@@ -63,6 +63,7 @@ CHAIN_DEPTH = 3
     STOP_CAP,
     STOP_FAULT,
     STOP_SCHED_CHAIN,
+    STOP_ROUND,
 ) = range(N_STOP_REASONS)
 
 i32 = jnp.int32

@@ -73,9 +73,9 @@ _SALT_MUL = jnp.int32(2654435761 % (2**31))
 # window.py for the stopper mechanics and docs/architecture.md for the table):
 #   horizon       first excluded event lies at/after the horizon (or nothing
 #                 is left to stop on — every pending event drained)
-#   nondrainable  a non-drainable event: txn start, lock-wait timeout, round
-#                 advance, chiller stage-2 re-dispatch, txn-completing ack,
-#                 release with a queued waiter
+#   nondrainable  a non-drainable event: txn start, lock-wait timeout,
+#                 chiller stage-2 re-dispatch, txn-completing ack, release
+#                 with a queued waiter
 #   scheduled     an in-window event schedules new work at or before the
 #                 window's timestamps (running-min rule) that the two-pass
 #                 chain admitter could NOT absorb — a genuine scheduling
@@ -106,6 +106,10 @@ _SALT_MUL = jnp.int32(2654435761 % (2**31))
 #                 scheduling fence, and counted `scheduled`. Together with
 #                 `SimState.chained` this splits the old `scheduled` row into
 #                 fence-stops (still `scheduled`) and chained-admits.
+#   round         a round advance: the fan-in that completes a non-final
+#                 round of an interactive transaction dispatches the next
+#                 round's statements, so it is pinned like `nondrainable`
+#                 and counted apart (horizon and fault stay dominant)
 STOP_REASONS = (
     "horizon",
     "nondrainable",
@@ -117,6 +121,7 @@ STOP_REASONS = (
     "cap",
     "fault",
     "sched_chain",
+    "round",
 )
 N_STOP_REASONS = len(STOP_REASONS)
 

@@ -13,7 +13,8 @@ Window stoppers (slot-accurate read/write sets — see docs/architecture.md):
 
 * non-drainable categories (txn start, lock-wait timeout, round advance,
   chiller stage-2 re-dispatch, txn-completing ack, release with a queued
-  waiter) pin their earliest-scheduled-time to 0;
+  waiter) pin their earliest-scheduled-time to 0; a round advance that ends
+  a window is counted as `round`, every other pin as `nondrainable`;
 * an event scheduling work at/before the window's timestamps (running-min
   rule over earliest-scheduled-times);
 * the second touch of one lock key (arrival / chain target / released
@@ -89,6 +90,7 @@ from repro.core.engine.chain import (
     STOP_LOCK_KEY,
     STOP_NONDRAINABLE,
     STOP_REL_OP,
+    STOP_ROUND,
     STOP_SCHED_CHAIN,
     STOP_SCHEDULED,
     _PlanVals,
@@ -703,6 +705,10 @@ def _window_plan(cfg: SimConfig, bank: Bank, s: SimState) -> _PlanVals:
     pinned_flat = jnp.concatenate(
         [pinned_term, pinned_sub.reshape(-1), pinned_op.reshape(-1)]
     )
+    # round advances are pinned sub slots, counted apart from the other pins
+    round_flat = jnp.concatenate(
+        [zt, advance_j.reshape(-1), jnp.zeros((T * K,), bool)]
+    )
     n_flat = jnp.concatenate([n_term, n_sub.reshape(-1), n_op.reshape(-1)])
     if F:
         # fault-schedule tails: pinned, schedule nothing, conflict with
@@ -719,6 +725,7 @@ def _window_plan(cfg: SimConfig, bank: Bank, s: SimState) -> _PlanVals:
         pinned_flat = jnp.concatenate(
             [pinned_flat, jnp.ones((F,), bool), jnp.zeros((D,), bool)]
         )
+        round_flat = jnp.concatenate([round_flat, zfd])
         # a firing probe re-arms at its slot time + interval; a non-firing
         # (or disarmed) one schedules nothing
         hb_fire = s.ds_down | (s.mw_heal > s.hb_time)
@@ -737,7 +744,7 @@ def _window_plan(cfg: SimConfig, bank: Bank, s: SimState) -> _PlanVals:
         STOP_HORIZON,
         jnp.where(
             pinned_flat,
-            STOP_NONDRAINABLE,
+            jnp.where(round_flat, STOP_ROUND, STOP_NONDRAINABLE),
             jnp.where(
                 conf_key,
                 STOP_LOCK_KEY,
